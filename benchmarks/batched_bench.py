@@ -7,14 +7,14 @@ compile) from *warm* (cached executable) cells/s — and records the
 throughputs plus a per-cell parity check: every cell's mean/max stretch
 must be *exactly* equal across the two paths (the backend's contract is
 bit-identity under x64, stronger than the 1e-9 relative tolerance the
-acceptance criterion asks for).  ``--compile-cache DIR`` additionally
-enables JAX's persistent compilation cache there, so re-invocations skip
-XLA compilation across processes.
+acceptance criterion asks for).  The lane keeps JAX's persistent
+compilation cache where ``JAX_COMPILATION_CACHE_DIR`` says, or else in the
+checkout's ``.jax_cache``, so re-invocations skip XLA compilation.
 
 CLI (used by the CI jax-smoke job)::
 
     PYTHONPATH=src python -m benchmarks.batched_bench --cells 8 \
-        --jobs 40 --nodes 16 --matvec pallas
+        --jobs 40 --nodes 16 --matvec interpret
 
 Exits non-zero on a parity mismatch only — throughput is recorded, never
 gated (the batched path is compile-dominated at smoke scale; its win is
@@ -26,8 +26,8 @@ import argparse
 import json
 import platform
 import sys
-from typing import Optional
 
+from repro.core.alloc_jax import MATVECS
 from repro.sched.sweep import grid, run_batched, run_grid
 from repro.workloads.registry import WorkloadSpec
 
@@ -37,37 +37,19 @@ BENCH_JSON = "BENCH_batched.json"
 POLICY = "GreedyP */OPT=MIN"
 
 
-def _enable_compilation_cache(cache_dir: Optional[str]) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir`` so repeat
-    invocations (CI re-runs, sweep restarts) skip XLA compilation entirely.
-    Returns the directory actually configured, or None if unavailable."""
-    if cache_dir is None:
-        return None
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache every program, however small/fast to compile: the lockstep
-        # sweep kernel is one program, and it is exactly what we re-run
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception:
-        return None
-    return cache_dir
-
-
 def run(bench: Bench, verbose: bool = True, n_cells: int = 100,
-        n_jobs: int = 25, n_nodes: int = 8, matvec: str = "auto",
-        cache_dir: Optional[str] = None) -> dict:
+        n_jobs: int = 25, n_nodes: int = 8, matvec: str = "jnp") -> dict:
     """One seeded grid through both sweep paths; parity + throughput.
 
     The batched pass runs *twice*: the first (cold) pays jit tracing +
-    XLA compilation — or a persistent-cache read when ``cache_dir`` is
-    warm from an earlier process — while the second (warm) hits the
+    XLA compilation — or a persistent-cache read when the compilation cache
+    is warm from an earlier process — while the second (warm) hits the
     in-process executable cache and measures pure lockstep throughput.
     Both are recorded; compile amortization is the whole point of the
     batched backend, so conflating the two in one number hides it.
     """
-    cache_dir = _enable_compilation_cache(cache_dir)
+    import jax  # noqa: PLC0415 — main() reports a missing jax first
+
     workloads = [WorkloadSpec("lublin", n_jobs=n_jobs, n_nodes=n_nodes,
                               seed=s) for s in range(n_cells)]
     cells = grid(workloads, [POLICY], ["baseline"])
@@ -88,7 +70,8 @@ def run(bench: Bench, verbose: bool = True, n_cells: int = 100,
         "bench": "batched",
         "config": {"n_cells": n_cells, "n_jobs": n_jobs, "n_nodes": n_nodes,
                    "policy": POLICY, "matvec": matvec,
-                   "compilation_cache_dir": cache_dir},
+                   "compilation_cache_dir":
+                       jax.config.jax_compilation_cache_dir},
         "batched_cells_per_sec": round(res_jax.cells_per_sec, 4),
         "batched_wall_s": round(res_jax.wall_s, 3),
         "batched_warm_cells_per_sec": round(res_warm.cells_per_sec, 4),
@@ -123,12 +106,7 @@ def main() -> int:
                     help="number of seeds in the grid (default 100)")
     ap.add_argument("--jobs", type=int, default=25)
     ap.add_argument("--nodes", type=int, default=8)
-    ap.add_argument("--matvec", default="auto",
-                    choices=["auto", "jnp", "pallas"])
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="persistent JAX compilation cache directory; a "
-                         "warm cache makes even the cold pass skip XLA "
-                         "compilation across processes/CI runs")
+    ap.add_argument("--matvec", default="jnp", choices=MATVECS)
     ap.add_argument("--no-check-parity", dest="check_parity",
                     action="store_false", default=True,
                     help="record parity but never fail on it")
@@ -136,14 +114,14 @@ def main() -> int:
 
     from repro.core.alloc_jax import has_jax
     if not has_jax():
-        print("jax not installed — batched bench skipped", file=sys.stderr)
-        return 0
+        print("jax not installed — the batched bench cannot run",
+              file=sys.stderr)
+        return 1
 
     from .common import QUICK
 
     payload = run(Bench(QUICK), n_cells=args.cells, n_jobs=args.jobs,
-                  n_nodes=args.nodes, matvec=args.matvec,
-                  cache_dir=args.compile_cache)
+                  n_nodes=args.nodes, matvec=args.matvec)
     if args.check_parity and not payload["stretch_parity"]:
         print(f"PARITY MISMATCH: {payload['n_mismatches']} cells diverge "
               f"from the numpy sweep (first: {payload['mismatches'][:1]})",

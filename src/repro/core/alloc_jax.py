@@ -11,10 +11,10 @@ bottleneck scan, masked freeze updates.  Finished lanes are masked out and
 idle until the slowest lane converges, so one compiled program serves the
 whole batch.
 
-Bit-identity contract (the same one ``alloc_kernels`` holds against
-``alloc_reference``): with ``jax_enable_x64``, every per-lane result is
-**bit-equal** to ``maxmin_yields_csr`` / ``avg_yields_csr`` on that lane's
-CSR alone.  Three properties make this work:
+Bit-identity contract on the CPU backend (the same one ``alloc_kernels``
+holds against ``alloc_reference``): with ``jax_enable_x64``, every
+per-lane result is **bit-equal** to ``maxmin_yields_csr`` /
+``avg_yields_csr`` on that lane's CSR alone.  Three properties make this work:
 
 * padding is exact — a padded column/row/lane contributes an exact
   ``+0.0`` to every accumulation, which never changes a finite partial sum,
@@ -25,9 +25,14 @@ CSR alone.  Three properties make this work:
   body into a single-rounding FMA — 1 ulp off numpy's two-rounding sequence
   — so the multiply must live outside the accumulation loop (see
   ``kernels/alloc_matvec.py``);
-* x64 is enabled through the *scoped* ``jax.experimental.enable_x64``
+* x64 is enabled through the *scoped* ``jax.enable_x64(True)`` config
   context, not the global flag, so the repo's float32 model/kernel stack is
   untouched in the same process.
+
+A TPU has no native float64: XLA emulates it with float32 pairs, which
+round even a host-to-device copy, so there the lanes agree with numpy to
+about 1e-14 relative, not bit for bit (ARCHITECTURE.md, "Exactness under
+jit", has the measurement and the tolerance ``chip_smoke.py`` holds).
 
 OPT=AVG is a HiGHS LP — a host simplex solver, not jittable — so the
 batched path computes the LP's yield floor (``1/max(1, Λ)``, Λ = max
@@ -35,11 +40,12 @@ sequential node load) on device for all lanes at once and solves the small
 per-lane LPs on host from bit-identical inputs; the results equal
 ``avg_yields_csr`` exactly.
 
-The matvec dispatches per the ``kernels/ops.py`` backend convention:
-``"jnp"`` (the pure-jnp formulation, default on CPU), ``"pallas"`` (the
-Pallas kernel, ``interpret=True`` off-TPU), or ``"auto"`` (Pallas only when
-the process-wide kernel backend is ``"pallas"`` and the batch is large
-enough to justify a kernel launch).
+The matvec is chosen by name, never by the backend the process found:
+``"jnp"`` (the pure-jnp formulation, the default on every backend) or
+``"interpret"`` (the Pallas kernel in the Pallas interpreter — a CPU
+validation path).  ``"pallas"`` asks for the compiled kernel and raises:
+Mosaic has no float64 and XLA's TPU x64 rewrite does not reach a Pallas
+custom call, so the kernel cannot run in the lane's dtype on the chip.
 
 On top sits the lockstep machinery ``sweep.run_batched`` drives: a
 :class:`BatchedAllocator` turning N allocation requests into one padded
@@ -48,10 +54,13 @@ dispatch (shapes bucketed to powers of two to bound recompiles), and a
 points until every live lane has a request in the batch.
 
 Everything imports lazily: environments without jax can import this module,
-and ``has_jax()`` gates the callers (``pytest.importorskip`` in tests).
+and ``has_jax()`` gates the callers (``pytest.importorskip`` in tests).  The
+first import also places JAX's persistent compilation cache (see
+:func:`_enable_compile_cache`).
 """
 from __future__ import annotations
 
+import os
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -73,6 +82,11 @@ __all__ = [
 ]
 
 _EPS = 1e-12
+MATVECS = ("jnp", "interpret", "pallas")
+#: the compile cache's place when ``JAX_COMPILATION_CACHE_DIR`` is unset: a
+#: fixed path in the checkout, so that the next process finds it again
+CACHE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         os.pardir, os.pardir, os.pardir, ".jax_cache")
 
 
 # --------------------------------------------------------------------------- #
@@ -86,7 +100,7 @@ def has_jax() -> bool:
     try:
         _jax()
         return True
-    except Exception:
+    except ImportError:
         return False
 
 
@@ -95,15 +109,29 @@ def _jax():
     if jax is None:
         import jax  # noqa: PLC0415 — lazy: tier-1 must pass without jax
 
+        _enable_compile_cache(jax)
         _STATE["jax"] = jax
     return _STATE["jax"]
 
 
+def _enable_compile_cache(jax) -> None:
+    """Keep compiled lockstep programs across processes.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left alone; otherwise the cache lives at :data:`CACHE_DIR`.  Every
+    program is cached however small or quick to compile: the lane is many
+    small bucketed programs, and those are exactly what a rerun repeats.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.normpath(CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+
+
 def _x64():
     """The scoped x64 context (thread-local — never the global flag)."""
-    from jax.experimental import enable_x64
-
-    return enable_x64()
+    return _jax().enable_x64(True)
 
 
 def _bucket(n: int, floor: int = 1) -> int:
@@ -177,30 +205,21 @@ def pad_batch(
 # --------------------------------------------------------------------------- #
 def _matvec_fn(matvec: str):
     """Resolve a matvec kind to a traced ``(weight, x) -> use`` callable."""
-    if matvec == "pallas":
+    if matvec == "jnp":
+        from ..kernels.alloc_matvec import alloc_matvec_ref
+
+        return alloc_matvec_ref
+    if matvec == "interpret":
         from ..kernels.alloc_matvec import alloc_matvec
 
-        interpret = _jax().default_backend() != "tpu"
-        return lambda w, x: alloc_matvec(w, x, interpret=interpret)
-    from ..kernels.alloc_matvec import alloc_matvec_ref
-
-    return alloc_matvec_ref
-
-
-def _resolve_matvec(matvec: str, n_nodes: int, width: int) -> str:
-    if matvec != "auto":
-        return matvec
-    # "auto": the Pallas kernel only pays off when the process opted into
-    # the pallas kernel backend (TPU runs) and the block is kernel-sized;
-    # interpret-mode Pallas on CPU is a correctness path, not a fast path.
-    try:
-        from ..kernels import ops
-
-        if ops.get_backend() == "pallas" and n_nodes * width >= 4096:
-            return "pallas"
-    except Exception:
-        pass
-    return "jnp"
+        return lambda w, x: alloc_matvec(w, x, interpret=True)
+    if matvec == "pallas":
+        raise ValueError(
+            "matvec='pallas': the compiled Pallas alloc_matvec cannot run in "
+            "the lane's float64 (Mosaic has no 64-bit float, and XLA's TPU "
+            "x64 rewrite does not reach a Pallas custom call); use "
+            "matvec='jnp', or 'interpret' to validate the kernel on CPU")
+    raise ValueError(f"unknown matvec backend {matvec!r}")
 
 
 def _build_maxmin(matvec: str):
@@ -294,7 +313,6 @@ def maxmin_yields_batch(
 ) -> np.ndarray:
     """OPT=MIN water-filling over a padded dense batch — one jitted lockstep
     dispatch.  Per lane bit-equal to ``maxmin_yields_csr`` under x64."""
-    matvec = _resolve_matvec(matvec, present.shape[1], present.shape[2])
     with _x64():
         y = _maxmin_jit(matvec)(present, weight, active)
         return np.asarray(y)
@@ -405,9 +423,8 @@ class BatchedAllocator:
     the host solver.
     """
 
-    def __init__(self, matvec: str = "auto"):
-        if matvec not in ("auto", "jnp", "pallas"):
-            raise ValueError(f"unknown matvec backend {matvec!r}")
+    def __init__(self, matvec: str = "jnp"):
+        _matvec_fn(matvec)              # unknown or unrunnable: raise now
         self.matvec = matvec
 
     # -- single request (the Engine alloc_backend protocol) ---------------- #
@@ -461,9 +478,8 @@ class BatchedAllocator:
 
     def _serve_avg(self, requests, idx, out):
         _, weight, _ = self._pad_compact(requests, idx)
-        matvec = _resolve_matvec(self.matvec, weight.shape[1], weight.shape[2])
         with _x64():
-            lams = np.asarray(_lam_jit(matvec)(weight))
+            lams = np.asarray(_lam_jit(self.matvec)(weight))
         for b, i in enumerate(idx):
             inc, cols, _ = requests[i]
             lam = float(lams[b]) if inc.n_nodes else 0.0

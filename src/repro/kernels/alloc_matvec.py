@@ -23,8 +23,13 @@ contracted, and an adds-only loop reproduces numpy's operation sequence
 exactly (padding columns contribute an exact ``+0.0``, which never changes
 a finite partial sum).  ``tests/test_alloc_jax.py`` pins this down.
 
-Following the ``kernels/ops.py`` pattern: ``interpret=True`` off-TPU
-(CPU validation), compiled on real TPU.
+The lane runs :func:`alloc_matvec_ref`.  :func:`alloc_matvec` is checked
+in the Pallas interpreter only (``interpret=True``, which the caller
+passes).  It does not compile for TPU in the lane's float64: XLA's TPU
+x64 rewrite refuses any Pallas custom call with f64 operands
+(``tests/test_tpu_compile.py`` pins that refusal).  The body is a second
+obstacle: under x64 its Pallas TPU lowering recurses without end, and in
+float32 Mosaic cannot lower the ``fori_loop``'s dynamic column slice.
 """
 from __future__ import annotations
 
@@ -54,27 +59,29 @@ def alloc_matvec_ref(weight, x):
 
 def _mv_kernel(w_ref, x_ref, o_ref):
     w = w_ref[0]                            # (N, W)
-    x = x_ref[0]                            # (W,)
-    prods = w * x[None, :]                  # separate multiply (see module doc)
+    x = x_ref[0]                            # (1, W)
+    prods = w * x                           # separate multiply (see module doc)
     N, W = w.shape
     def body(j, acc):
         return acc + prods[:, j]
-    o_ref[0] = lax.fori_loop(0, W, body, jnp.zeros((N,), w.dtype))
+    o_ref[0] = lax.fori_loop(0, W, body, jnp.zeros((N,), w.dtype))[None, :]
 
 
-def alloc_matvec(weight, x, *, interpret: bool = True):
+def alloc_matvec(weight, x, *, interpret: bool):
     """Pallas version of :func:`alloc_matvec_ref`: grid over lanes, one
-    sequential accumulation per (lane, node) block."""
+    sequential accumulation per (lane, node) block.  ``x`` and the output
+    carry a unit middle axis so that every block's last two dimensions
+    equal the array's, as the TPU tiling requires."""
     weight, x = jnp.asarray(weight), jnp.asarray(x)
     B, N, W = weight.shape
     if W == 0:
         return jnp.zeros((B, N), weight.dtype)
     return pl.pallas_call(
         _mv_kernel,
-        out_shape=jax.ShapeDtypeStruct((B, N), weight.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, N), weight.dtype),
         grid=(B,),
         in_specs=[pl.BlockSpec((1, N, W), lambda b: (b, 0, 0)),
-                  pl.BlockSpec((1, W), lambda b: (b, 0))],
-        out_specs=pl.BlockSpec((1, N), lambda b: (b, 0)),
+                  pl.BlockSpec((1, 1, W), lambda b: (b, 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, N), lambda b: (b, 0, 0)),
         interpret=interpret,
-    )(weight, x)
+    )(weight, x[:, None, :])[:, 0]

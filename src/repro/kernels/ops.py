@@ -2,10 +2,11 @@
 
 Models call these; the backend is chosen once per process:
 
-* ``"ref"``     — pure-jnp oracles (CPU execution, dry-run lowering; the
-                  default off-TPU so compiled HLO stays backend-portable);
-* ``"pallas"``  — Pallas kernels, ``interpret=True`` off-TPU (correctness
-                  validation) and compiled on real TPU.
+* ``"ref"``       — pure-jnp oracles (the default: CPU execution, dry-run
+                    lowering, compiled HLO that stays backend-portable);
+* ``"pallas"``    — Pallas kernels, compiled (TPU only);
+* ``"interpret"`` — Pallas kernels in the Pallas interpreter (correctness
+                    validation on CPU).  Never chosen for the caller.
 
 Gradients always flow through the ref formulation (``custom_vjp`` with the
 oracle backward), which keeps training correct while the forward hot-path
@@ -26,9 +27,8 @@ _BACKEND = "ref"
 
 def set_backend(name: str) -> None:
     global _BACKEND
-    if name not in ("ref", "pallas"):
+    if name not in ("ref", "pallas", "interpret"):
         raise ValueError(name)
-    global _BACKEND
     _BACKEND = name
 
 
@@ -37,7 +37,7 @@ def get_backend() -> str:
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return _BACKEND == "interpret"
 
 
 # --------------------------------------------------------------------------- #
@@ -45,7 +45,7 @@ def _interpret() -> bool:
 # --------------------------------------------------------------------------- #
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_offset: int = 0, scale: Optional[float] = None):
-    if _BACKEND == "pallas":
+    if _BACKEND != "ref":
         from .flash_attention import flash_attention as fa
 
         fwd = functools.partial(fa, causal=causal, window=window,
@@ -72,7 +72,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 def flash_decode(q, k_cache, v_cache, cur_len, *, scale: Optional[float] = None):
-    if _BACKEND == "pallas":
+    if _BACKEND != "ref":
         from .flash_attention import flash_decode as fd
 
         return fd(q, k_cache, v_cache, cur_len, scale=scale,
@@ -84,7 +84,7 @@ def flash_decode(q, k_cache, v_cache, cur_len, *, scale: Optional[float] = None)
 # RWKV6 WKV scan                                                               #
 # --------------------------------------------------------------------------- #
 def wkv6(r, k, v, w, u, s0):
-    if _BACKEND == "pallas" and r.shape[1] > 1:
+    if _BACKEND != "ref" and r.shape[1] > 1:
         from .rwkv6_scan import wkv6 as kk
 
         fwd = functools.partial(kk, interpret=_interpret())
@@ -110,9 +110,10 @@ def wkv6(r, k, v, w, u, s0):
 # --------------------------------------------------------------------------- #
 def alloc_matvec(weight, x):
     """Sequential masked matvec over job columns — bit-exact vs the numpy
-    CSR accumulation (see ``kernels/alloc_matvec.py``).  No custom_vjp: the
+    CSR accumulation (see ``kernels/alloc_matvec.py``, which also says why
+    the compiled kernel cannot take float64 on TPU).  No custom_vjp: the
     scheduler path is forward-only f64 arithmetic, never differentiated."""
-    if _BACKEND == "pallas":
+    if _BACKEND != "ref":
         from .alloc_matvec import alloc_matvec as kk
 
         return kk(weight, x, interpret=_interpret())
@@ -123,7 +124,7 @@ def alloc_matvec(weight, x):
 # RG-LRU linear recurrence                                                     #
 # --------------------------------------------------------------------------- #
 def linear_recurrence(a, b, h0):
-    if _BACKEND == "pallas" and a.shape[1] > 1:
+    if _BACKEND != "ref" and a.shape[1] > 1:
         from .rglru_scan import rglru_scan as kk
 
         fwd = functools.partial(kk, interpret=_interpret())

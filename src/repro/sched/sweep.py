@@ -551,8 +551,9 @@ def run_branches(
     * ``quarantine`` — in the default serial in-process mode, turn a
       crashing branch into a quarantine record instead of propagating
       (the supervised and batched paths always isolate failures).
-    * ``backend="jax"``/``"pallas"`` — race all branches through one
-      lockstep batched allocation device (see :func:`run_batched`).
+    * ``backend="jax"`` — race all branches through one lockstep batched
+      allocation device (see :func:`run_batched`; ``"pallas"`` asks for
+      the compiled Pallas matvec there, which raises).
 
     Records gain ``horizon_s``, ``branch_seed``, ``early_stopped``,
     ``partial`` and ``period`` next to the PR-5 branch fields.
@@ -683,7 +684,7 @@ def run_batched(
     cells: Sequence[Cell],
     compute_bound: bool = False,
     json_path: Optional[str] = None,
-    matvec: str = "auto",
+    matvec: str = "jnp",
     quarantine: bool = False,
 ) -> SweepResult:
     """Evaluate every cell through the batched JAX allocation backend.
@@ -693,15 +694,18 @@ def run_batched(
     its allocation backend; the driver thread collects every live lane's
     §4.6 request per scheduling round, pads them into one dense batch, and
     answers the round with a single jitted water-filling dispatch (OPT=AVG
-    floors batched on device, LPs on host).  Per-lane results are bit-equal
-    to the numpy kernels, so the records match a ``run_grid`` sweep of the
-    same cells exactly on every simulation outcome (records carry
-    ``backend="jax"`` and their own wall times).
+    floors batched on device, LPs on host).  On the CPU backend per-lane
+    results are bit-equal to the numpy kernels, so the records match a
+    ``run_grid`` sweep of the same cells exactly on every simulation
+    outcome; records carry ``backend="jax"`` and their own wall times.
+    On a TPU, whose float64 is emulated, the continuous metrics agree to
+    about 1e-14 relative instead.
 
-    ``matvec`` picks the inner-matvec kernel: ``"jnp"`` (pure jnp, the
-    CPU default), ``"pallas"`` (the Pallas kernel, ``interpret=True``
-    off-TPU), or ``"auto"`` (pallas only under the process-wide pallas
-    kernel backend, at kernel-worthy shapes).
+    ``matvec`` picks the inner-matvec kernel: ``"jnp"`` (pure jnp, on
+    every backend) or ``"interpret"`` (the Pallas kernel in the Pallas
+    interpreter, for CPU validation); ``"pallas"`` raises, since the
+    compiled kernel cannot run in the lane's float64 (see
+    :mod:`repro.core.alloc_jax`).
 
     A lane that raises re-raises on the driver thread by default (the other
     lanes are still released); with ``quarantine=True`` the failed lane
@@ -776,10 +780,13 @@ def run_grid(
     bound of its (scenario-transformed) trace and the achieved
     ``degradation`` from it.  ``json_path`` additionally writes the artifact.
 
-    ``backend="jax"`` (or ``"pallas"``) routes the whole grid through
-    :func:`run_batched` instead — one device, allocation phases stepped in
+    ``backend="jax"`` routes the whole grid through :func:`run_batched`
+    instead (``"pallas"`` asks for the compiled Pallas matvec, which
+    raises) — one device, allocation phases stepped in
     lockstep, bit-identical records; ``n_workers``/``chunksize`` don't
-    apply there.  ``None``/``"numpy"`` is the process-pool path.
+    apply there.  A failed lane re-raises there unless the sweep is
+    supervised, which turns it into a quarantine record.
+    ``None``/``"numpy"`` is the process-pool path.
 
     ``timeout_s``/``retries`` turn the driver into a supervisor: each cell
     gets a wall-clock budget (``timeout_s``, ``None`` = unlimited) and up to

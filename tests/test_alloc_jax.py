@@ -150,7 +150,7 @@ def test_batched_allocator_mixed_opts():
 def test_pallas_matvec_bit_equal_csr():
     """The Pallas interpret kernel reproduces the sequential CSR matvec bit
     for bit (the adds-only formulation defeats XLA's FMA contraction)."""
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.kernels.alloc_matvec import alloc_matvec, alloc_matvec_ref
 
@@ -166,7 +166,7 @@ def test_pallas_matvec_bit_equal_csr():
         x = rng.random(W)
         xs[b] = x
         incs_x.append((inc, x))
-    with enable_x64():
+    with jax.enable_x64(True):
         got_pl = np.asarray(alloc_matvec(weight, xs, interpret=True))
         got_ref = np.asarray(alloc_matvec_ref(weight, xs))
     for b, (inc, x) in enumerate(incs_x):
@@ -179,13 +179,13 @@ def test_maxmin_pallas_matvec_bit_equal():
     rng = np.random.default_rng(29)
     for _ in range(6):
         inc, active = random_instance(rng, max_width=16, max_nodes=8)
-        got = alloc_jax.maxmin_yields_jax(inc, active, matvec="pallas")
+        got = alloc_jax.maxmin_yields_jax(inc, active, matvec="interpret")
         assert np.array_equal(got, maxmin_yields_csr(inc, active))
 
 
 def test_ops_dispatch_alloc_matvec():
-    """kernels.ops.alloc_matvec: ref and pallas backends agree bitwise."""
-    from jax.experimental import enable_x64
+    """kernels.ops.alloc_matvec: ref and interpreted Pallas agree bitwise."""
+    import jax
 
     from repro.kernels import ops
 
@@ -194,14 +194,64 @@ def test_ops_dispatch_alloc_matvec():
     x = rng.random((3, 10))
     prev = ops.get_backend()
     try:
-        with enable_x64():
+        with jax.enable_x64(True):
             ops.set_backend("ref")
             a = np.asarray(ops.alloc_matvec(weight, x))
-            ops.set_backend("pallas")
+            ops.set_backend("interpret")
             b = np.asarray(ops.alloc_matvec(weight, x))
     finally:
         ops.set_backend(prev)
     assert np.array_equal(a, b)
+
+
+def test_matvec_choice_is_explicit():
+    """No backend sniffing: "pallas" (compiled) refuses the lane's float64
+    up front, and "auto" no longer exists."""
+    with pytest.raises(ValueError, match="float64"):
+        alloc_jax.BatchedAllocator(matvec="pallas")
+    cells = grid([WorkloadSpec("lublin", n_jobs=10, n_nodes=4, seed=0)],
+                 ["GreedyP */OPT=MIN"])
+    with pytest.raises(ValueError, match="float64"):
+        run_grid(cells, backend="pallas")
+    with pytest.raises(ValueError, match="unknown matvec"):
+        alloc_jax.BatchedAllocator(matvec="auto")
+
+
+def test_has_jax_hides_only_a_missing_jax(monkeypatch):
+    def missing():
+        raise ImportError("no jax")
+
+    def broken():
+        raise RuntimeError("backend failed to initialize")
+
+    monkeypatch.setattr(alloc_jax, "_jax", missing)
+    assert not alloc_jax.has_jax()
+    monkeypatch.setattr(alloc_jax, "_jax", broken)
+    with pytest.raises(RuntimeError):
+        alloc_jax.has_jax()
+
+
+def test_compile_cache_placement(monkeypatch):
+    """The cache goes where JAX_COMPILATION_CACHE_DIR says, else to the
+    fixed path in the checkout — never a path built per process."""
+    import os
+
+    import jax
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        alloc_jax._enable_compile_cache(jax)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            root, ".jax_cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", "/elsewhere")
+        alloc_jax._enable_compile_cache(jax)
+        assert jax.config.jax_compilation_cache_dir == "/elsewhere"
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 # --------------------------------------------------------------------------- #
@@ -306,6 +356,11 @@ def test_run_batched_propagates_errors():
                 "NoSuchPolicy")]
     with pytest.raises(ValueError, match="NoSuchPolicy"):
         run_batched(bad + cells)
+    with pytest.raises(ValueError, match="NoSuchPolicy"):
+        run_grid(bad + cells, backend="jax")
+    res = run_grid(bad + cells, backend="jax", retries=1)
+    assert [bool(r.get("quarantined")) for r in res.records] == [
+        True, False, False]
 
 
 from repro.sched.sweep import Cell  # noqa: E402  (used above)
