@@ -51,7 +51,10 @@ On top sits the lockstep machinery ``sweep.run_batched`` drives: a
 :class:`BatchedAllocator` turning N allocation requests into one padded
 dispatch (shapes bucketed to powers of two to bound recompiles), and a
 :class:`LockstepDispatcher` that parks engine threads at their allocation
-points until every live lane has a request in the batch.
+points until every live lane has a request in the batch.  Each round it
+serves is a row of the process's round log, timed by ``dfrs.*`` spans that
+also land in any profiler trace (:mod:`repro.core.roundlog`;
+:func:`lockstep_rounds`, :func:`lockstep_totals`).
 
 Everything imports lazily: environments without jax can import this module,
 and ``has_jax()`` gates the callers (``pytest.importorskip`` in tests).  The
@@ -62,11 +65,14 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from .alloc_kernels import CSRIncidence
+from .roundlog import Round, lockstep_rounds, lockstep_totals, span
+from . import roundlog
 
 __all__ = [
     "has_jax",
@@ -79,6 +85,9 @@ __all__ = [
     "JaxAllocBackend",
     "BatchedAllocator",
     "LockstepDispatcher",
+    "Round",
+    "lockstep_rounds",
+    "lockstep_totals",
 ]
 
 _EPS = 1e-12
@@ -314,8 +323,10 @@ def maxmin_yields_batch(
     """OPT=MIN water-filling over a padded dense batch — one jitted lockstep
     dispatch.  Per lane bit-equal to ``maxmin_yields_csr`` under x64."""
     with _x64():
-        y = _maxmin_jit(matvec)(present, weight, active)
-        return np.asarray(y)
+        with span("dfrs.dispatch"):
+            y = _maxmin_jit(matvec)(present, weight, active)
+        with span("dfrs.fetch"):
+            return np.asarray(y)
 
 
 def maxmin_yields_jax(
@@ -456,17 +467,20 @@ class BatchedAllocator:
         """Compact each request to its running columns and pad the set into
         one bucketed batch (per-lane exactness makes the co-batching safe:
         no lane's answer depends on what else is in the batch)."""
-        N = _bucket(max(requests[i][0].n_nodes for i in idx))
-        W = _bucket(max(requests[i][1].shape[0] for i in idx), 8)
-        B = _bucket(len(idx))
-        present = np.zeros((B, N, W), dtype=bool)
-        weight = np.zeros((B, N, W), dtype=np.float64)
-        active = np.zeros((B, W), dtype=bool)
-        for b, i in enumerate(idx):
-            inc, cols, _ = requests[i]
-            p, w = densify_csr(inc, n_nodes=N, cols=cols, width=W)
-            present[b], weight[b] = p, w
-            active[b, : cols.shape[0]] = True
+        with span("dfrs.pad"):
+            N = _bucket(max(requests[i][0].n_nodes for i in idx))
+            W = _bucket(max(requests[i][1].shape[0] for i in idx), 8)
+            B = _bucket(len(idx))
+            present = np.zeros((B, N, W), dtype=bool)
+            weight = np.zeros((B, N, W), dtype=np.float64)
+            active = np.zeros((B, W), dtype=bool)
+            for b, i in enumerate(idx):
+                inc, cols, _ = requests[i]
+                p, w = densify_csr(inc, n_nodes=N, cols=cols, width=W)
+                present[b], weight[b] = p, w
+                active[b, : cols.shape[0]] = True
+        roundlog.count(cells=B * N * W, nnz=sum(
+            requests[i][0].indices.shape[0] for i in idx))
         return present, weight, active
 
     def _serve_min(self, requests, idx, out):
@@ -478,12 +492,13 @@ class BatchedAllocator:
 
     def _serve_avg(self, requests, idx, out):
         _, weight, _ = self._pad_compact(requests, idx)
-        with _x64():
+        with _x64(), span("dfrs.lam"):
             lams = np.asarray(_lam_jit(self.matvec)(weight))
         for b, i in enumerate(idx):
             inc, cols, _ = requests[i]
             lam = float(lams[b]) if inc.n_nodes else 0.0
-            out[i] = _avg_lp(inc, cols, 1.0 / max(1.0, lam))
+            with span("dfrs.lp"):
+                out[i] = _avg_lp(inc, cols, 1.0 / max(1.0, lam))
 
 
 class JaxAllocBackend(BatchedAllocator):
@@ -507,6 +522,12 @@ class LockstepDispatcher:
     driver answers each round with one ``BatchedAllocator.allocate_many``
     and wakes the lanes.  Per-lane results are bit-independent of batch
     composition, so the lockstep schedule cannot change any cell's outcome.
+
+    Every round is a row of the round log (:mod:`repro.core.roundlog`): the
+    barrier wait (``dfrs.barrier_wait``), the allocator call
+    (``dfrs.allocate``), the requests, and the lane CPU each request cost
+    since its lane's last answer.  The last wait of a pass is a row with no
+    request.
     """
 
     def __init__(self, n_lanes: int, allocator: BatchedAllocator):
@@ -514,6 +535,7 @@ class LockstepDispatcher:
         self.allocator = allocator
         self._cond = threading.Condition()
         self._pending: Dict[int, Tuple[CSRIncidence, np.ndarray, str]] = {}
+        self._lane_cpu: Dict[int, float] = {}
         self._results: Dict[int, object] = {}
         self._finished: set = set()
         self._broken: Optional[BaseException] = None
@@ -527,11 +549,12 @@ class LockstepDispatcher:
             self._finished.add(i)
             self._cond.notify_all()
 
-    def _request(self, i, inc, cols, opt) -> np.ndarray:
+    def _request(self, i, inc, cols, opt, cpu_s: float) -> np.ndarray:
         with self._cond:
             if self._broken is not None:
                 raise self._broken
             self._pending[i] = (inc, cols, opt)
+            self._lane_cpu[i] = cpu_s
             self._cond.notify_all()
             self._cond.wait_for(
                 lambda: i in self._results or self._broken is not None)
@@ -544,22 +567,33 @@ class LockstepDispatcher:
         """Drive rounds until every lane finished.  Call from the thread
         that owns the device (the sweep driver)."""
         while True:
-            with self._cond:
+            with span("dfrs.barrier_wait") as wait, self._cond:
                 self._cond.wait_for(
                     lambda: len(self._pending) + len(self._finished)
                     >= self.n_lanes)
-                if not self._pending:
-                    return              # every lane finished
                 batch = sorted(self._pending.items())
+                lane_cpu = sum(self._lane_cpu.values())
                 self._pending.clear()
+                self._lane_cpu.clear()
+            if not batch:               # every lane finished
+                roundlog.record(wait.t0, wait.t1, wait.t1, 0, 0, 0.0)
+                return
             lanes = [i for i, _ in batch]
+            acc = roundlog.open_round()
             try:
-                answers = self.allocator.allocate_many([r for _, r in batch])
+                with span("dfrs.allocate") as call:
+                    answers = self.allocator.allocate_many(
+                        [r for _, r in batch])
             except BaseException as exc:
                 with self._cond:        # poison: wake every parked/future lane
                     self._broken = exc
                     self._cond.notify_all()
                 raise
+            finally:
+                roundlog.close_round()
+            roundlog.record(wait.t0, call.t0, call.t1, len(batch),
+                            sum(opt == "MIN" for _, (_, _, opt) in batch),
+                            lane_cpu, acc)
             with self._cond:
                 for i, y in zip(lanes, answers):
                     self._results[i] = y
@@ -568,16 +602,25 @@ class LockstepDispatcher:
 
 class _Lane:
     """The per-engine view of a :class:`LockstepDispatcher` (the object an
-    ``Engine`` receives as ``alloc_backend``)."""
+    ``Engine`` receives as ``alloc_backend``).  Made and used in the lane's
+    own thread: each request carries the thread's CPU seconds since its
+    last answer (the first, since the lane was made), so its wake-ups at
+    the barrier are left out.  That takes two reads of the thread's CPU
+    clock a request, each a system call (about 6 µs on a virtualized
+    host)."""
 
-    __slots__ = ("_dispatcher", "index")
+    __slots__ = ("_dispatcher", "index", "_cpu_mark")
 
     def __init__(self, dispatcher: LockstepDispatcher, index: int):
         self._dispatcher = dispatcher
         self.index = index
+        self._cpu_mark = time.thread_time()
 
     def allocate(self, inc: CSRIncidence, cols: np.ndarray,
                  opt: str = "MIN") -> np.ndarray:
         if not cols.shape[0]:
             return np.zeros(0)          # nothing running: no round trip
-        return self._dispatcher._request(self.index, inc, cols, opt)
+        cpu_s = time.thread_time() - self._cpu_mark
+        y = self._dispatcher._request(self.index, inc, cols, opt, cpu_s)
+        self._cpu_mark = time.thread_time()
+        return y
