@@ -37,8 +37,8 @@ jit", has the measurement and the tolerance ``chip_smoke.py`` holds).
 OPT=AVG is a HiGHS LP — a host simplex solver, not jittable — so the
 batched path computes the LP's yield floor (``1/max(1, Λ)``, Λ = max
 sequential node load) on device for all lanes at once and solves the small
-per-lane LPs on host from bit-identical inputs; the results equal
-``avg_yields_csr`` exactly.
+per-lane LPs on host from bit-identical inputs through the same
+``lp2_yields`` call; the results equal ``avg_yields_csr`` exactly.
 
 The matvec is chosen by name, never by the backend the process found:
 ``"jnp"`` (the pure-jnp formulation, the default on every backend) or
@@ -70,7 +70,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .alloc_kernels import CSRIncidence
+from .alloc_kernels import CSRIncidence, lp2_yields
 from .roundlog import Round, lockstep_rounds, lockstep_totals, span
 from . import roundlog
 
@@ -400,25 +400,6 @@ def _lam_jit(matvec: str):
     return fn
 
 
-def _avg_lp(inc: CSRIncidence, cols: np.ndarray, y_min: float) -> np.ndarray:
-    """The LP (2) solve of ``avg_yields_csr`` with the floor injected (the
-    floor is the only device-computed input; from bit-identical ``y_min``
-    the host solve is the identical scipy call)."""
-    from scipy.optimize import linprog
-
-    m = int(cols.shape[0])
-    res = linprog(
-        c=-np.ones(m),
-        A_ub=inc.scipy_csr(cols),
-        b_ub=np.ones(inc.n_nodes),
-        bounds=[(y_min, 1.0)] * m,
-        method="highs",
-    )
-    if not res.success:  # numerically degenerate: the safe floor
-        return np.full(m, y_min)
-    return np.clip(res.x, 0.0, 1.0)
-
-
 # --------------------------------------------------------------------------- #
 # engine-pluggable backends                                                    #
 # --------------------------------------------------------------------------- #
@@ -498,7 +479,7 @@ class BatchedAllocator:
             inc, cols, _ = requests[i]
             lam = float(lams[b]) if inc.n_nodes else 0.0
             with span("dfrs.lp"):
-                out[i] = _avg_lp(inc, cols, 1.0 / max(1.0, lam))
+                out[i] = lp2_yields(inc, cols, 1.0 / max(1.0, lam))
 
 
 class JaxAllocBackend(BatchedAllocator):

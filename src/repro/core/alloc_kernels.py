@@ -15,7 +15,10 @@ cell.  This module replaces that with:
   with dirty-row tracking so a CSR snapshot costs only the changed rows;
 * :func:`maxmin_yields_csr` — §4.6 water-filling as whole-array sparse
   matvecs (per-node frozen use and unfrozen need) with one freeze round per
-  pass instead of nested per-item Python loops.
+  pass instead of nested per-item Python loops;
+* :func:`lp2_yields` — the OPT=AVG LP (2), handed straight to HiGHS as the
+  model and options ``scipy.optimize.linprog(method="highs")`` would build,
+  without its Python front end.
 
 Bit-identity contract: every kernel here reproduces the reference
 implementations in :mod:`repro.core.alloc_reference` *bit for bit*.  The
@@ -33,9 +36,12 @@ equivalence tests run every cell both ways and require identical
 from __future__ import annotations
 
 import contextlib
+from functools import lru_cache
 from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
+
+from . import roundlog
 
 __all__ = [
     "CSRIncidence",
@@ -43,6 +49,7 @@ __all__ = [
     "build_csr",
     "maxmin_yields_csr",
     "avg_yields_csr",
+    "lp2_yields",
     "reference_kernels",
     "reference_kernels_active",
 ]
@@ -128,15 +135,6 @@ class CSRIncidence:
     def row_jobs(self, node: int) -> np.ndarray:
         """Job columns resident on ``node`` (ascending)."""
         return self.indices[self.indptr[node]:self.indptr[node + 1]]
-
-    def scipy_csr(self, cols: np.ndarray):
-        """Scipy CSR restricted to ``cols`` (sorted job columns) for the LP
-        passes; equals the reference lil-built constraint matrix."""
-        from scipy.sparse import csr_matrix
-
-        pos = np.searchsorted(cols, self.indices)
-        return csr_matrix((self.data, pos, self.indptr),
-                          shape=(self.n_nodes, cols.shape[0]))
 
 
 def build_csr(cpu_need: Sequence[float],
@@ -339,21 +337,76 @@ def avg_yields_csr(inc: CSRIncidence, cols: np.ndarray) -> np.ndarray:
     ``cols`` — sorted job columns participating (the running set).  Returns
     yields aligned with ``cols``.
     """
-    from scipy.optimize import linprog
-
-    m = int(cols.shape[0])
-    if m == 0:
+    if cols.shape[0] == 0:
         return np.zeros(0)
     load_need = inc.matvec(np.ones(inc.width))
     lam = float(load_need.max()) if inc.n_nodes else 0.0
-    y_min = 1.0 / max(1.0, lam)
-    res = linprog(
-        c=-np.ones(m),
-        A_ub=inc.scipy_csr(cols),
-        b_ub=np.ones(inc.n_nodes),
-        bounds=[(y_min, 1.0)] * m,
-        method="highs",
-    )
-    if not res.success:  # numerically degenerate: fall back to the safe floor
+    return lp2_yields(inc, cols, 1.0 / max(1.0, lam))
+
+
+@lru_cache(maxsize=None)
+def _highs():
+    """scipy's HiGHS core and the options ``linprog(method="highs")`` sets,
+    imported and built on the first LP: importing ``scipy.optimize`` adds
+    about half a second to the program's start (x86 CPU host), which a run
+    without OPT=AVG need not pay."""
+    # The HiGHS core that scipy bundles and that linprog itself calls.
+    # The module is private to scipy; tests/test_alloc_lp.py pins every
+    # answer of lp2_yields bit for bit to linprog's.
+    from scipy.optimize._highspy import _core as highs
+
+    opts = highs.HighsOptions()
+    opts.presolve = "on"
+    opts.simplex_strategy = int(
+        highs.simplex_constants.SimplexStrategy.kSimplexStrategyDual)
+    opts.highs_debug_level = int(highs.HighsDebugLevel.kHighsDebugLevelNone)
+    opts.output_flag = False
+    opts.log_to_console = False
+    return highs, opts
+
+
+def lp2_yields(inc: CSRIncidence, cols: np.ndarray, y_min: float) -> np.ndarray:
+    """LP (2) with the floor given: maximize the sum of the yields of
+    ``cols`` subject to every node's load ``<= 1`` and ``y_min <= y <= 1``.
+
+    One HiGHS solve of the very model ``linprog(method="highs")`` builds —
+    the constraint matrix in canonical CSC, rows bounded by ``(-inf, 1]`` —
+    under the same options, on a fresh solver, so no basis carries from one
+    LP to the next.  A solve HiGHS does not report optimal gets the floor
+    and counts in the round's ``lp_nonoptimal``.  Returns yields aligned
+    with ``cols``.
+    """
+    highs, opts = _highs()
+    m = int(cols.shape[0])
+    n = inc.n_nodes
+    # CSR -> CSC: a stable sort by column keeps each column's rows ascending
+    pos = np.searchsorted(cols, inc.indices)
+    order = np.argsort(pos, kind="stable")
+    rows = np.repeat(np.arange(n, dtype=np.int32), np.diff(inc.indptr))
+    start = np.zeros(m + 1, dtype=np.int32)
+    np.cumsum(np.bincount(pos, minlength=m), out=start[1:])
+
+    lp = highs.HighsLp()
+    lp.num_col_ = m
+    lp.num_row_ = n
+    lp.col_cost_ = np.full(m, -1.0)
+    lp.col_lower_ = np.full(m, y_min)
+    lp.col_upper_ = np.ones(m)
+    lp.row_lower_ = np.full(n, -highs.kHighsInf)
+    lp.row_upper_ = np.ones(n)
+    a = lp.a_matrix_
+    a.num_col_ = m
+    a.num_row_ = n
+    a.format_ = highs.MatrixFormat.kColwise
+    a.start_ = start
+    a.index_ = rows[order]
+    a.value_ = inc.data[order]
+
+    solver = highs._Highs()
+    error = highs.HighsStatus.kError
+    if (solver.passOptions(opts) == error
+            or solver.passModel(lp) == error or solver.run() == error
+            or solver.getModelStatus() != highs.HighsModelStatus.kOptimal):
+        roundlog.count(lp_nonoptimal=1)
         return np.full(m, y_min)
-    return np.clip(res.x, 0.0, 1.0)
+    return np.clip(np.array(solver.getSolution().col_value), 0.0, 1.0)

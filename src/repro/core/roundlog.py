@@ -20,7 +20,8 @@ The spans, innermost first:
 * ``dfrs.dispatch`` — moving the padded batch to the device and launching
   the solve;
 * ``dfrs.lam`` — the OPT=AVG floor's device call and fetch;
-* ``dfrs.lp`` — one host LP of an OPT=AVG request;
+* ``dfrs.lp`` — one host LP of an OPT=AVG request (counts the LPs that
+  got the floor because HiGHS did not report them optimal);
 * ``dfrs.pad`` — compacting and padding a batch (counts its padded
   B·N·W cells and the nonzeros they carry);
 * ``dfrs.allocate`` — the whole allocator call of a round;
@@ -67,6 +68,7 @@ class Round(NamedTuple):
     lp_s: float
     lps: int                # host LPs solved
     lane_cpu_s: float       # Σ of the requests' lane CPU since their last answer
+    lp_nonoptimal: int = 0  # of the LPs, those HiGHS did not solve to optimal
 
     @property
     def wait_s(self) -> float:
@@ -81,7 +83,8 @@ class Round(NamedTuple):
 _TIMED = {"dfrs.pad": "pad_s", "dfrs.dispatch": "dispatch_s",
           "dfrs.fetch": "fetch_s", "dfrs.lam": "lam_s", "dfrs.lp": "lp_s"}
 _SUMMED = ("requests", "min_requests", "cells", "nnz", "pad_s",
-           "dispatch_s", "fetch_s", "lam_s", "lp_s", "lps", "lane_cpu_s")
+           "dispatch_s", "fetch_s", "lam_s", "lp_s", "lps", "lane_cpu_s",
+           "lp_nonoptimal")
 
 
 class RoundLog:
@@ -215,6 +218,7 @@ def record(wait_t0: float, alloc_t0: float, alloc_t1: float, requests: int,
                 cells=acc.counts.get("cells", 0),
                 nnz=acc.counts.get("nnz", 0),
                 lps=acc.calls.get("dfrs.lp", 0), lane_cpu_s=float(lane_cpu_s),
+                lp_nonoptimal=acc.counts.get("lp_nonoptimal", 0),
                 **{field: acc.seconds.get(name, 0.0)
                    for name, field in _TIMED.items()})
     LOG.append(row)

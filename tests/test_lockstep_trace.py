@@ -126,6 +126,16 @@ def test_lane_cpu_and_totals(sweep_pass):
         sum(r.lane_cpu_s for r in rows))
 
 
+def test_lp_nonoptimal_in_rows_and_totals(sweep_pass):
+    rows, seen = sweep_pass["rows"], sweep_pass["seen"]
+    assert sum(r.lps for r in rows) == seen.opts.count("AVG") > 0
+    assert all(r.lp_nonoptimal == 0 for r in rows)
+    before, after = sweep_pass["before"], sweep_pass["after"]
+    assert "lp_nonoptimal" in after
+    assert after["lp_nonoptimal"] - before["lp_nonoptimal"] == 0
+    assert after["lps"] - before["lps"] == seen.opts.count("AVG")
+
+
 def test_answers_unchanged(sweep_pass):
     ref = run_grid(sweep_pass["cells"])
     assert _outcomes(sweep_pass["got"]) == _outcomes(ref)
