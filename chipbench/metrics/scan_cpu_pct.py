@@ -1,0 +1,13 @@
+"""The lanes' CPU inside resume scans (the program's ``dfrs.resume_scan``)
+as a share of all their CPU between requests, over the traced window."""
+from chipbench.rounds import window_rounds
+
+
+def read(ctx):
+    rows = window_rounds(ctx)
+    if not rows or not hasattr(rows[0], "scan_cpu_s"):
+        return None
+    lane = sum(r.lane_cpu_s for r in rows)
+    if lane <= 0:
+        return None
+    return 100.0 * sum(r.scan_cpu_s for r in rows) / lane

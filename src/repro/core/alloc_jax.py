@@ -507,7 +507,10 @@ class LockstepDispatcher:
     Every round is a row of the round log (:mod:`repro.core.roundlog`): the
     barrier wait (``dfrs.barrier_wait``), the allocator call
     (``dfrs.allocate``), the requests, and the lane CPU each request cost
-    since its lane's last answer.  The last wait of a pass is a row with no
+    since its lane's last answer, with what the lane's engine counted in
+    that time (its :class:`~repro.core.roundlog.LaneMeter`); what a lane
+    counted after its last request goes with the next row, so the log sums
+    to the lanes' whole counts.  The last wait of a pass is a row with no
     request.
     """
 
@@ -517,6 +520,9 @@ class LockstepDispatcher:
         self._cond = threading.Condition()
         self._pending: Dict[int, Tuple[CSRIncidence, np.ndarray, str]] = {}
         self._lane_cpu: Dict[int, float] = {}
+        self._lane_counts: Dict[int, tuple] = {}
+        self._meters: Dict[int, roundlog.LaneMeter] = {}
+        self._leftover: List[tuple] = []
         self._results: Dict[int, object] = {}
         self._finished: set = set()
         self._broken: Optional[BaseException] = None
@@ -525,17 +531,24 @@ class LockstepDispatcher:
         return _Lane(self, i)
 
     def finish_lane(self, i: int) -> None:
-        """A lane's engine is done (or died) — it leaves the barrier."""
+        """A lane's engine is done (or died) — it leaves the barrier, with
+        what its meter counted since its last request."""
         with self._cond:
+            meter = self._meters.pop(i, None)
+            if meter is not None:
+                self._leftover.append(meter.take())
+                roundlog.unbind_lane_meter(meter)
             self._finished.add(i)
             self._cond.notify_all()
 
-    def _request(self, i, inc, cols, opt, cpu_s: float) -> np.ndarray:
+    def _request(self, i, inc, cols, opt, cpu_s: float,
+                 counts: tuple) -> np.ndarray:
         with self._cond:
             if self._broken is not None:
                 raise self._broken
             self._pending[i] = (inc, cols, opt)
             self._lane_cpu[i] = cpu_s
+            self._lane_counts[i] = counts
             self._cond.notify_all()
             self._cond.wait_for(
                 lambda: i in self._results or self._broken is not None)
@@ -554,10 +567,14 @@ class LockstepDispatcher:
                     >= self.n_lanes)
                 batch = sorted(self._pending.items())
                 lane_cpu = sum(self._lane_cpu.values())
+                counts = list(self._lane_counts.values()) + self._leftover
                 self._pending.clear()
                 self._lane_cpu.clear()
+                self._lane_counts.clear()
+                self._leftover = []
             if not batch:               # every lane finished
-                roundlog.record(wait.t0, wait.t1, wait.t1, 0, 0, 0.0)
+                roundlog.record(wait.t0, wait.t1, wait.t1, 0, 0, 0.0,
+                                lanes=counts)
                 return
             lanes = [i for i, _ in batch]
             acc = roundlog.open_round()
@@ -574,7 +591,7 @@ class LockstepDispatcher:
                 roundlog.close_round()
             roundlog.record(wait.t0, call.t0, call.t1, len(batch),
                             sum(opt == "MIN" for _, (_, _, opt) in batch),
-                            lane_cpu, acc)
+                            lane_cpu, acc, lanes=counts)
             with self._cond:
                 for i, y in zip(lanes, answers):
                     self._results[i] = y
@@ -588,13 +605,19 @@ class _Lane:
     last answer (the first, since the lane was made), so its wake-ups at
     the barrier are left out.  That takes two reads of the thread's CPU
     clock a request, each a system call (about 6 µs on a virtualized
-    host)."""
+    host).  The lane makes the thread's
+    :class:`~repro.core.roundlog.LaneMeter`, which an engine made on the
+    thread counts into, and hands its counts with each request."""
 
-    __slots__ = ("_dispatcher", "index", "_cpu_mark")
+    __slots__ = ("_dispatcher", "index", "_cpu_mark", "meter")
 
     def __init__(self, dispatcher: LockstepDispatcher, index: int):
         self._dispatcher = dispatcher
         self.index = index
+        self.meter = roundlog.LaneMeter()
+        roundlog.bind_lane_meter(self.meter)
+        with dispatcher._cond:
+            dispatcher._meters[index] = self.meter
         self._cpu_mark = time.thread_time()
 
     def allocate(self, inc: CSRIncidence, cols: np.ndarray,
@@ -602,6 +625,7 @@ class _Lane:
         if not cols.shape[0]:
             return np.zeros(0)          # nothing running: no round trip
         cpu_s = time.thread_time() - self._cpu_mark
-        y = self._dispatcher._request(self.index, inc, cols, opt, cpu_s)
+        y = self._dispatcher._request(self.index, inc, cols, opt, cpu_s,
+                                      self.meter.take())
         self._cpu_mark = time.thread_time()
         return y

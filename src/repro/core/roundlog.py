@@ -28,6 +28,15 @@ The spans, innermost first:
 * ``dfrs.barrier_wait`` — the serving thread waiting for every live lane's
   request (disjoint from ``dfrs.allocate``).
 
+Each lane's engine counts into a :class:`Meter`: the waiting jobs its
+resume scans tried and started, its MCB8 packs, its pauses and migrations.
+In a lane's own thread the meter is a :class:`LaneMeter`, which also times
+each resume scan and each pack with one pair of ``time.thread_time()``
+reads under a ``dfrs.resume_scan`` / ``dfrs.pack`` profiler annotation; the
+lane hands what it counted to the barrier with each request, as it hands
+its CPU, and the round's row carries the sums.  Elsewhere (the numpy path)
+a count is one attribute increment and nothing is timed.
+
 An operator reads :func:`lockstep_totals` (sums since process start) or
 :func:`lockstep_rounds` (the rows of a stretch of the host's
 ``perf_counter`` clock).  Nothing here imports jax until a span is entered:
@@ -35,13 +44,14 @@ spans sit only on the device path, which needs jax anyway.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import deque
-from typing import Dict, List, NamedTuple, Optional
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
-__all__ = ["Round", "RoundLog", "LOG", "span", "count",
-           "lockstep_rounds", "lockstep_totals"]
+__all__ = ["Round", "RoundLog", "LOG", "span", "count", "Meter",
+           "LaneMeter", "meter_for", "lockstep_rounds", "lockstep_totals"]
 
 #: rows the ring keeps: several minutes of rounds at 200 rounds/s
 CAPACITY = 1 << 16
@@ -69,6 +79,14 @@ class Round(NamedTuple):
     lps: int                # host LPs solved
     lane_cpu_s: float       # Σ of the requests' lane CPU since their last answer
     lp_nonoptimal: int = 0  # of the LPs, those HiGHS did not solve to optimal
+    # what the requests' lanes did since their last answer (their Meters)
+    scan_cpu_s: float = 0.0  # lane CPU inside resume scans
+    scanned: int = 0         # waiting jobs the resume scans tried
+    started: int = 0         # of them, started
+    pack_cpu_s: float = 0.0  # lane CPU inside MCB8 packs
+    packs: int = 0
+    pauses: int = 0
+    migrations: int = 0
 
     @property
     def wait_s(self) -> float:
@@ -82,9 +100,12 @@ class Round(NamedTuple):
 #: the span whose seconds fill each timed field of a row
 _TIMED = {"dfrs.pad": "pad_s", "dfrs.dispatch": "dispatch_s",
           "dfrs.fetch": "fetch_s", "dfrs.lam": "lam_s", "dfrs.lp": "lp_s"}
+#: what a lane's engine counts, in the order :meth:`Meter.take` gives it
+LANE_FIELDS = ("scan_cpu_s", "scanned", "started", "pack_cpu_s", "packs",
+               "pauses", "migrations")
 _SUMMED = ("requests", "min_requests", "cells", "nnz", "pad_s",
            "dispatch_s", "fetch_s", "lam_s", "lp_s", "lps", "lane_cpu_s",
-           "lp_nonoptimal")
+           "lp_nonoptimal") + LANE_FIELDS
 
 
 class RoundLog:
@@ -210,8 +231,10 @@ def close_round() -> None:
 
 def record(wait_t0: float, alloc_t0: float, alloc_t1: float, requests: int,
            min_requests: int, lane_cpu_s: float,
-           acc: Optional[_Open] = None) -> Round:
-    """Append one round to :data:`LOG` and return it."""
+           acc: Optional[_Open] = None,
+           lanes: Sequence[Sequence[float]] = ()) -> Round:
+    """Append one round to :data:`LOG` and return it; ``lanes`` are the
+    :meth:`Meter.take` tuples the lanes handed in, summed into the row."""
     acc = acc if acc is not None else _Open()
     row = Round(wait_t0=wait_t0, alloc_t0=alloc_t0, alloc_t1=alloc_t1,
                 requests=int(requests), min_requests=int(min_requests),
@@ -220,6 +243,91 @@ def record(wait_t0: float, alloc_t0: float, alloc_t1: float, requests: int,
                 lps=acc.calls.get("dfrs.lp", 0), lane_cpu_s=float(lane_cpu_s),
                 lp_nonoptimal=acc.counts.get("lp_nonoptimal", 0),
                 **{field: acc.seconds.get(name, 0.0)
-                   for name, field in _TIMED.items()})
+                   for name, field in _TIMED.items()},
+                **{field: sum(lane[k] for lane in lanes)
+                   for k, field in enumerate(LANE_FIELDS)})
     LOG.append(row)
     return row
+
+
+# --------------------------------------------------------------------------- #
+# a lane's engine: its resume scans, packs, pauses and migrations              #
+# --------------------------------------------------------------------------- #
+_UNTIMED = contextlib.nullcontext()
+
+
+class Meter:
+    """An engine's counts of what its policy did: the waiting jobs its
+    resume scans tried (``scanned``) and started, its MCB8 packs, its
+    pauses and migrations.  This one times nothing: :meth:`clock` is a
+    no-op."""
+
+    __slots__ = LANE_FIELDS
+
+    def __init__(self):
+        for field in LANE_FIELDS:
+            setattr(self, field, 0)
+
+    def clock(self, name: str, field: str):
+        """Time a stretch of the engine's work into ``field`` (a lane
+        meter); here a no-op."""
+        return _UNTIMED
+
+    def take(self) -> tuple:
+        """The counts since the last take, in :data:`LANE_FIELDS` order;
+        the meter starts again from zero."""
+        out = tuple(getattr(self, field) for field in LANE_FIELDS)
+        for field in LANE_FIELDS:
+            setattr(self, field, 0)
+        return out
+
+
+class _Clock:
+    """One thread-CPU reading pair under a profiler annotation."""
+
+    __slots__ = ("meter", "name", "field", "_note", "_t0")
+
+    def __init__(self, meter: "Meter", name: str, field: str):
+        self.meter, self.name, self.field = meter, name, field
+
+    def __enter__(self) -> None:
+        self._note = _annotation()(self.name)
+        self._note.__enter__()
+        self._t0 = time.thread_time()
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.thread_time() - self._t0
+        setattr(self.meter, self.field, getattr(self.meter, self.field) + dt)
+        self._note.__exit__(*exc)
+        return False
+
+
+class LaneMeter(Meter):
+    """The meter of a lockstep lane's engine, made in the lane's thread:
+    :meth:`clock` adds the thread's CPU seconds of the stretch to a field
+    and annotates it for the profiler."""
+
+    __slots__ = ()
+
+    def clock(self, name: str, field: str) -> _Clock:
+        return _Clock(self, name, field)
+
+
+def bind_lane_meter(meter: LaneMeter) -> None:
+    """Make ``meter`` the calling thread's lane meter."""
+    _LOCAL.lane_meter = meter
+
+
+def unbind_lane_meter(meter: LaneMeter) -> None:
+    """If ``meter`` is the calling thread's lane meter, the thread serves
+    no lane any more."""
+    if getattr(_LOCAL, "lane_meter", None) is meter:
+        _LOCAL.lane_meter = None
+
+
+def meter_for(backend) -> Meter:
+    """The meter an engine with allocation backend ``backend`` counts into:
+    the calling thread's lane meter when the thread serves a lockstep lane
+    and the engine has a backend, else a meter of the engine's own."""
+    lane = getattr(_LOCAL, "lane_meter", None)
+    return lane if lane is not None and backend is not None else Meter()

@@ -363,11 +363,13 @@ def _apply_mcb8(e, spec: Optional[PolicySpec]) -> None:
     cands = e.state.uncompleted()
     if not cands:
         return
-    res = mcb8(
-        cands, e.params.n_nodes, e.state.now,
-        pinned=_pinned(e, spec), alive=e.state.alive,
-    )
-    _apply_global_mapping(e, res.mappings, cands)
+    e.meter.packs += 1
+    with e.meter.clock("dfrs.pack", "pack_cpu_s"):
+        res = mcb8(
+            cands, e.params.n_nodes, e.state.now,
+            pinned=_pinned(e, spec), alive=e.state.alive,
+        )
+        _apply_global_mapping(e, res.mappings, cands)
 
 
 # --------------------------------------------------------------------------- #
@@ -428,15 +430,19 @@ class CompleteGreedy(Component):
 
     def on_complete(self) -> None:
         e = self.p.e
-        waiting = sorted(
-            (j for j in e.state.uncompleted() if j.status in (PENDING, PAUSED)),
-            key=lambda j: j.priority_key(e.state.now),
-            reverse=True,
-        )
-        for js in waiting:
-            mapping = greedy_place(e.state.pool.copy(), js.spec)
-            if mapping is not None:
-                e.start(js, mapping)
+        waiting = [j for j in e.state.uncompleted()
+                   if j.status in (PENDING, PAUSED)]
+        if not waiting:
+            return
+        meter = e.meter
+        meter.scanned += len(waiting)
+        with meter.clock("dfrs.resume_scan", "scan_cpu_s"):
+            waiting.sort(key=lambda j: j.priority_key(e.state.now),
+                         reverse=True)
+            for js in waiting:
+                mapping = greedy_place(e.state.pool.copy(), js.spec)
+                if mapping is not None and e.start(js, mapping):
+                    meter.started += 1
 
 
 @register_component("complete", "mcb8")

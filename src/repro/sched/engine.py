@@ -41,6 +41,7 @@ from ..core.greedy import greedy_p, greedy_place, greedy_pm
 from ..core.job import COMPLETED, PAUSED, PENDING, RUNNING, JobSpec
 from ..core.mcb8 import mcb8
 from ..core.policies import PolicySpec, parse_policy
+from ..core.roundlog import meter_for
 from ..core.state import (
     S_CANCELLED,
     S_COMPLETED,
@@ -510,6 +511,9 @@ class Engine:
         self.bytes_moved_gb = 0.0
         self.n_pmtn = 0
         self.n_mig = 0
+        # the policy's resume scans, packs, pauses and migrations; a lockstep
+        # lane's meter when this engine runs in one (repro.core.roundlog)
+        self.meter = meter_for(alloc_backend)
         self._events = 0
         self.policy.validate(self.state.specs, self.params)
         self.policy.bind(self)
@@ -530,6 +534,7 @@ class Engine:
         js.yld = 0.0
         js.n_pmtn += 1
         self.n_pmtn += 1
+        self.meter.pauses += 1
         self.bytes_moved_gb += self._job_mem_gb(js.spec)  # save image
 
     def start(self, js: JobView, mapping: List[int]) -> bool:
@@ -580,6 +585,7 @@ class Engine:
                 continue
             js.n_mig += 1
             self.n_mig += 1
+            self.meter.migrations += 1
             js.penalty_until = self.state.now + self.params.penalty
             self.bytes_moved_gb += 2.0 * self._job_mem_gb(js.spec, moved)
 

@@ -45,6 +45,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from ..core.job import JobSpec
+from ..core.roundlog import Meter
 from ..core.state import (S_CANCELLED, S_COMPLETED, S_NOT_ARRIVED, S_PAUSED,
                           S_PENDING)
 from ..workloads.trace import Trace, as_trace
@@ -1174,6 +1175,7 @@ class SimSession:
         # allocator backends are process-local objects, not snapshot state:
         # restored engines always resume on the default numpy hot path
         e.alloc_backend = None
+        e.meter = Meter()
         from ..core.state import EngineState
         e.state = EngineState(specs, params.n_nodes)
         e.cluster_events = [

@@ -38,6 +38,7 @@ import numpy as np
 from ..core.bound import max_stretch_lower_bound
 from ..core.ioutil import atomic_write_json
 from ..core.policies import parse_policy
+from ..core.roundlog import meter_for
 from ..workloads.registry import WorkloadSpec, make_trace_ir
 from .engine import Engine, SimParams
 from .scenarios import apply_scenario_trace, parse_scenario_chain
@@ -449,6 +450,7 @@ def _run_branch(task: Tuple[int, "_Branch", Any],
         ses.narrator.reseed(br.branch_seed)
     if alloc_backend is not None:
         ses.engine.alloc_backend = alloc_backend
+        ses.engine.meter = meter_for(alloc_backend)
     target = (math.inf if br.horizon_s is None
               else br.snap.time + float(br.horizon_s))
     stopped = False
