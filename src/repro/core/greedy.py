@@ -1,6 +1,8 @@
 """Greedy task-mapping heuristics (paper §4.2).
 
 * ``greedy_place``     — map an incoming job without disturbing running jobs.
+* ``greedy_fits``      — whether ``greedy_place`` would map it, from a
+                         read of the pool's free memory alone.
 * ``greedy_p``         — GreedyP: additionally pause lower-priority running
                          jobs (by increasing priority) to force admission.
 * ``greedy_pm``        — GreedyPM: like GreedyP, but paused victims get a
@@ -25,7 +27,8 @@ import numpy as np
 from . import alloc_kernels, alloc_reference
 from .job import JobSpec, JobState, NodePool
 
-__all__ = ["greedy_place", "GreedyAdmission", "greedy_p", "greedy_pm"]
+__all__ = ["greedy_place", "greedy_fits", "GreedyAdmission", "greedy_p",
+           "greedy_pm"]
 
 
 def greedy_place(pool: NodePool, spec: JobSpec) -> Optional[List[int]]:
@@ -53,6 +56,32 @@ def greedy_place(pool: NodePool, spec: JobSpec) -> Optional[List[int]]:
         mem_free[node] -= mem_req
         masked[node] = load[node] if mem_free[node] >= thr else np.inf
     return mapping
+
+
+def greedy_fits(pool: NodePool, spec: JobSpec) -> bool:
+    """Exactly ``greedy_place(pool.copy(), spec) is not None``, from a read
+    of ``pool.mem_free`` alone (nothing is written, nothing copied whole).
+
+    Greedy fails only on memory: CPU may be oversubscribed, and a node keeps
+    taking tasks while the repeated float64 ``mem_free[n] -= mem_req`` leaves
+    it at ``mem_req - 1e-12`` or more.  So the job fits iff those per-node
+    counts, taken with the same subtractions, sum to ``n_tasks``; neither the
+    loads nor the order greedy visits the nodes matters.  As rounded
+    subtraction is monotone, a node's count never rises with ``mem_req``:
+    if ``(n, m)`` does not fit a pool, no ``(n' >= n, m' >= m)`` does."""
+    mem_req = spec.mem_req
+    thr = mem_req - 1e-12
+    left = spec.n_tasks
+    free = pool.mem_free
+    while True:
+        ok = free >= thr
+        took = int(np.count_nonzero(ok))
+        left -= took
+        if left <= 0:
+            return True
+        if not took:
+            return False
+        free = free[ok] - mem_req
 
 
 @dataclass

@@ -29,7 +29,8 @@ The spans, innermost first:
   request (disjoint from ``dfrs.allocate``).
 
 Each lane's engine counts into a :class:`Meter`: the waiting jobs its
-resume scans tried and started, its MCB8 packs, its pauses and migrations.
+resume scans walked, put through ``greedy_place`` and started, its MCB8
+packs, its pauses and migrations.
 In a lane's own thread the meter is a :class:`LaneMeter`, which also times
 each resume scan and each pack with one pair of ``time.thread_time()``
 reads under a ``dfrs.resume_scan`` / ``dfrs.pack`` profiler annotation; the
@@ -81,8 +82,9 @@ class Round(NamedTuple):
     lp_nonoptimal: int = 0  # of the LPs, those HiGHS did not solve to optimal
     # what the requests' lanes did since their last answer (their Meters)
     scan_cpu_s: float = 0.0  # lane CPU inside resume scans
-    scanned: int = 0         # waiting jobs the resume scans tried
+    scanned: int = 0         # waiting jobs the resume scans walked
     started: int = 0         # of them, started
+    placed: int = 0          # of them, put through greedy_place on a copy
     pack_cpu_s: float = 0.0  # lane CPU inside MCB8 packs
     packs: int = 0
     pauses: int = 0
@@ -101,8 +103,8 @@ class Round(NamedTuple):
 _TIMED = {"dfrs.pad": "pad_s", "dfrs.dispatch": "dispatch_s",
           "dfrs.fetch": "fetch_s", "dfrs.lam": "lam_s", "dfrs.lp": "lp_s"}
 #: what a lane's engine counts, in the order :meth:`Meter.take` gives it
-LANE_FIELDS = ("scan_cpu_s", "scanned", "started", "pack_cpu_s", "packs",
-               "pauses", "migrations")
+LANE_FIELDS = ("scan_cpu_s", "scanned", "started", "placed", "pack_cpu_s",
+               "packs", "pauses", "migrations")
 _SUMMED = ("requests", "min_requests", "cells", "nnz", "pad_s",
            "dispatch_s", "fetch_s", "lam_s", "lp_s", "lps", "lane_cpu_s",
            "lp_nonoptimal") + LANE_FIELDS
@@ -258,9 +260,9 @@ _UNTIMED = contextlib.nullcontext()
 
 class Meter:
     """An engine's counts of what its policy did: the waiting jobs its
-    resume scans tried (``scanned``) and started, its MCB8 packs, its
-    pauses and migrations.  This one times nothing: :meth:`clock` is a
-    no-op."""
+    resume scans walked (``scanned``), put through ``greedy_place``
+    (``placed``) and started, its MCB8 packs, its pauses and migrations.
+    This one times nothing: :meth:`clock` is a no-op."""
 
     __slots__ = LANE_FIELDS
 

@@ -42,7 +42,7 @@ from collections import Counter, deque
 from itertools import islice
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-from ..core.greedy import greedy_p, greedy_place, greedy_pm
+from ..core.greedy import greedy_fits, greedy_p, greedy_place, greedy_pm
 from ..core.job import PAUSED, PENDING, RUNNING, JobSpec
 from ..core.mcb8 import mcb8
 from ..core.policies import PolicySpec, parse_policy
@@ -426,7 +426,12 @@ class SubmitMCB8(Component):
 
 @register_component("complete", "greedy")
 class CompleteGreedy(Component):
-    """Opportunistically greedy-start waiting jobs by §4.1 priority."""
+    """Opportunistically greedy-start waiting jobs by §4.1 priority.
+
+    Only a job that :func:`greedy_fits` gets a pool copy and a
+    ``greedy_place``.  The pool changes only when a job starts, so until
+    then a job with as many tasks and as much memory per task as one that
+    did not fit is skipped untested (``greedy_fits``'s dominance)."""
 
     def on_complete(self) -> None:
         e = self.p.e
@@ -439,10 +444,19 @@ class CompleteGreedy(Component):
         with meter.clock("dfrs.resume_scan", "scan_cpu_s"):
             waiting.sort(key=lambda j: j.priority_key(e.state.now),
                          reverse=True)
+            failed: List[Tuple[int, float]] = []   # since the last start
             for js in waiting:
+                n, m = js.spec.n_tasks, js.spec.mem_req
+                if any(n >= fn and m >= fm for fn, fm in failed):
+                    continue
+                if not greedy_fits(e.state.pool, js.spec):
+                    failed.append((n, m))
+                    continue
+                meter.placed += 1
                 mapping = greedy_place(e.state.pool.copy(), js.spec)
                 if mapping is not None and e.start(js, mapping):
                     meter.started += 1
+                    failed.clear()
 
 
 @register_component("complete", "mcb8")

@@ -171,6 +171,16 @@ GOLDEN_CASES = [(w, p, sc)
                 for p in GOLDEN_POLICIES
                 for sc in ("baseline", "rack_failure")]
 GOLDEN_CASES.append((GOLDEN_WORKLOADS[0], "/stretch-per/OPT=MAX", "baseline"))
+# the contended end, where the composed resume scan skips the jobs that
+# cannot fit and the seed's tries every one; trace 0 leaves a short backlog,
+# trace 1 a long one at most completions
+LOADED_WORKLOADS = [WorkloadSpec("lublin", n_jobs=120, n_nodes=16, seed=s,
+                                 load=0.9) for s in (0, 1)]
+GOLDEN_CASES += [(w, p, sc)
+                 for w in LOADED_WORKLOADS
+                 for p in ("GreedyP */OPT=MIN",
+                           "GreedyPM */per/OPT=MIN/MINVT=600")
+                 for sc in ("baseline", "rack_failure")]
 
 
 @pytest.mark.parametrize(
@@ -181,10 +191,16 @@ def test_golden_composed_vs_seed_simresult(workload, policy, scenario):
     specs, events = apply_scenario(scenario, specs, workload.n_nodes,
                                    seed=workload.seed)
     params = SimParams(n_nodes=workload.n_nodes)
-    composed = Engine(specs, policy, params, cluster_events=events).run()
+    engine = Engine(specs, policy, params, cluster_events=events)
+    composed = engine.run()
     seed = Engine(specs, make_seed_policy(parse_policy(policy)), params,
                   cluster_events=events).run()
     assert _result_dict(composed) == _result_dict(seed)
+    m = engine.meter
+    assert m.started <= m.placed <= m.scanned
+    if workload is LOADED_WORKLOADS[1]:
+        # the scan pruned: most waiting jobs never reached greedy_place
+        assert 0 < m.started <= m.placed < m.scanned / 2
 
 
 def test_default_engine_policy_is_composed():

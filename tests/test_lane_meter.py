@@ -13,6 +13,7 @@ import pytest
 from chipbench import bench
 from repro import api
 from repro.core import alloc_jax, roundlog
+from repro.core.job import PAUSED, PENDING
 from repro.sched import components
 from repro.sched.engine import Engine, SimParams
 from repro.workloads.registry import WorkloadSpec, make_trace_ir
@@ -20,7 +21,7 @@ from repro.workloads.registry import WorkloadSpec, make_trace_ir
 POLICIES = ["GreedyP */per/OPT=MIN/MINVT=600",
             "GreedyPM */per/OPT=MIN/MINVT=600"]
 READERS = ("scan_cpu_pct.sweep", "pack_cpu_pct.sweep",
-           "scanned_per_request.sweep")
+           "scanned_per_request.sweep", "scan_placed_pct.sweep")
 
 
 def _loaded(seed):
@@ -41,7 +42,8 @@ def test_lockstep_sums_are_the_records_counts():
     got = {k: after[k] - before[k] for k in roundlog.LANE_FIELDS}
     assert got["pauses"] == sum(r["n_pmtn"] for r in records) > 0
     assert got["migrations"] == sum(r["n_mig"] for r in records) > 0
-    assert got["packs"] > 0 and got["scanned"] >= got["started"] > 0
+    assert got["packs"] > 0
+    assert got["scanned"] > got["placed"] >= got["started"] > 0
     assert 0 < got["scan_cpu_s"] + got["pack_cpu_s"] <= after["lane_cpu_s"]
     # the rows of the pass carry the same sums
     for field in roundlog.LANE_FIELDS:
@@ -78,7 +80,10 @@ def test_branches_count_from_their_fork():
 
 @pytest.mark.parametrize("policy", POLICIES)
 def test_numpy_path_counts_what_the_resume_scan_tried(policy, monkeypatch):
-    tried, started = [0], [0]
+    """``scanned`` counts the waiting jobs each scan walked, ``placed``
+    those it put through ``greedy_place``: the fit test and failure
+    dominance keep the rest from it."""
+    walked, tried, started = [0], [0], [0]
     inner_place = components.greedy_place
     inner_scan = components.CompleteGreedy.on_complete
     inside = [False]
@@ -91,6 +96,8 @@ def test_numpy_path_counts_what_the_resume_scan_tried(policy, monkeypatch):
         return mapping
 
     def scan(self):
+        walked[0] += sum(j.status in (PENDING, PAUSED)
+                         for j in self.p.e.state.uncompleted())
         inside[0] = True
         try:
             inner_scan(self)
@@ -102,8 +109,10 @@ def test_numpy_path_counts_what_the_resume_scan_tried(policy, monkeypatch):
     e = Engine(make_trace_ir(_loaded(1)), policy, SimParams(n_nodes=32))
     r = e.run()
     assert type(e.meter) is roundlog.Meter
-    assert e.meter.scanned == tried[0] > 0
+    assert e.meter.scanned == walked[0] > 0
+    assert e.meter.placed == tried[0] > 0
     assert e.meter.started == started[0] > 0
+    assert e.meter.placed <= e.meter.scanned
     assert e.meter.pauses == r.n_pmtn and e.meter.migrations == r.n_mig
     assert e.meter.packs > 0
     # nothing timed off a lane
@@ -125,8 +134,8 @@ def test_meter_take_starts_again():
 def test_rows_without_the_new_fields_still_read():
     row = roundlog.Round(0.0, 1.0, 2.0, 3, 3, 10, 5, 0.1, 0.2, 0.3, 0.0,
                          0.0, 0, 0.5)
-    assert (row.scan_cpu_s, row.scanned, row.packs, row.pauses,
-            row.migrations) == (0.0, 0, 0, 0, 0)
+    assert (row.scan_cpu_s, row.scanned, row.placed, row.packs, row.pauses,
+            row.migrations) == (0.0, 0, 0, 0, 0, 0)
     log = roundlog.RoundLog()
     log.append(row)
     totals = log.totals()
@@ -148,3 +157,32 @@ def test_readers_find_nothing_in_rows_without_the_fields(name, monkeypatch):
     span = namedtuple("Span", "t0 t1")(0.0, 1.0)
     assert bench.load_metric(name)(bench.Context([span], 1.0, None, {})) \
         is None
+
+
+def test_placed_share_is_absent_where_rows_only_count_the_walk(monkeypatch):
+    """A program whose lanes count `scanned` but not `placed` (the scan
+    before the fit test) reads no share, not 0 % or 100 %."""
+    Mid = namedtuple("Mid", "wait_t0 alloc_t0 alloc_t1 requests lane_cpu_s "
+                            "scanned")
+    monkeypatch.setattr(alloc_jax, "lockstep_rounds",
+                        lambda t0, t1: [Mid(0.0, 0.5, 1.0, 4, 0.2, 12)])
+    span = namedtuple("Span", "t0 t1")(0.0, 1.0)
+    read = bench.load_metric("scan_placed_pct.sweep")
+    assert read(bench.Context([span], 1.0, None, {})) is None
+
+
+def test_traced_scaled_sweep_reads_the_placed_share():
+    """A traced `lublin.scaled-sweep` run, driven on the CPU on a smaller
+    cluster at the cell's offered load of 0.9, reports the share."""
+    import jax
+    from chipbench import control
+
+    cell = bench.Cell("lublin.scaled-sweep")
+    cell.config = dict(cell.config, n_nodes=32, n_jobs=100)
+    cell.traffic = dict(cell.traffic, trace={"after_s": 0.2, "length_s": 0.5})
+    device = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
+    out = bench.run_cell(cell, 2**31 + 54321, 0.5, True, jax, device,
+                         time.perf_counter(), solver=control.solver("program"),
+                         log=lambda msg: None)
+    assert out["correct"] is True, out["checks"]
+    assert 0 <= out["metrics"]["scan_placed_pct.sweep"]["value"] < 100

@@ -6,7 +6,8 @@ pytest.importorskip("hypothesis", reason="property tests need hypothesis "
                     "(pip install -r requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
 
-from repro.core.greedy import greedy_p, greedy_place, greedy_pm
+from repro.core import alloc_reference
+from repro.core.greedy import greedy_fits, greedy_p, greedy_place, greedy_pm
 from repro.core.job import JobSpec, JobState, NodePool, RUNNING
 from repro.core.mcb8 import mcb8, mcb8_pack
 from repro.core.policies import (TABLE1_POLICIES, all_paper_policies,
@@ -69,6 +70,140 @@ def test_greedy_place_rolls_back_on_failure():
     before = pool.mem_free.copy()
     assert greedy_place(pool, s) is None
     np.testing.assert_allclose(pool.mem_free, before)
+
+
+class _Probe:
+    """A job's shape alone, so that a fit test may ask for no memory at all
+    (``JobSpec`` wants ``mem_req`` above 0)."""
+
+    def __init__(self, n_tasks, mem_req, cpu_need=0.25):
+        self.n_tasks, self.mem_req, self.cpu_need = n_tasks, mem_req, cpu_need
+
+
+def _worn_pool(seed):
+    """A pool after a long history of greedy places and removals, with a
+    few nodes' memory zeroed as a failure leaves it."""
+    rng = np.random.default_rng(seed)
+    n_nodes = int(rng.integers(1, 25))
+    pool, placed = NodePool(n_nodes), []
+    for step in range(400):
+        if placed and rng.random() < 0.45:
+            spec, mapping = placed.pop(int(rng.integers(len(placed))))
+            pool.remove(spec, mapping)
+            continue
+        spec = JobSpec(jid=step, release=0.0, proc_time=1.0,
+                       n_tasks=int(rng.integers(1, 2 * n_nodes + 2)),
+                       cpu_need=float(rng.choice([0.25, 0.5, 1.0])),
+                       mem_req=float(rng.choice(
+                           [rng.uniform(0.01, 1.0), 0.1, 0.3, 0.7, 1.0])))
+        mapping = greedy_place(pool, spec)
+        if mapping is not None:
+            placed.append((spec, mapping))
+    for node in rng.choice(n_nodes, size=n_nodes // 5, replace=False):
+        pool.mem_free[node] = 0.0
+    return pool, rng
+
+
+def _edge_mem_reqs(pool):
+    """Memory requests whose threshold ``m - 1e-12`` lands within a few
+    ulps of a node's free memory, on both sides, after its first and its
+    second task."""
+    out = []
+    for f in np.unique(pool.mem_free)[:6]:
+        for k in (1.0, 2.0):
+            m = (float(f) + 1e-12) / k
+            for _ in range(3):
+                m = np.nextafter(m, -np.inf)
+            for _ in range(7):
+                if m > 0:
+                    out.append(float(m))
+                m = np.nextafter(m, np.inf)
+    return out
+
+
+def _fits_all_ways(pool, spec):
+    fast = greedy_place(pool.copy(), spec) is not None
+    assert fast == (alloc_reference.greedy_place(pool.copy(), spec)
+                    is not None)
+    return fast
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_greedy_fits_is_greedy_place_on_a_copy(seed):
+    pool, rng = _worn_pool(seed)
+    load, mem_free = pool.load.tobytes(), pool.mem_free.tobytes()
+    mem_reqs = ([float(m) for m in rng.uniform(0.0, 1.0, 12)]
+                + [0.1, 0.3, 0.5, 1.0, 1e-300, 1e-12, 0.0]
+                + _edge_mem_reqs(pool))
+    seen = set()
+    for m in mem_reqs:
+        for n in sorted({1, 2, pool.n, pool.n + 1, 3 * pool.n + 5,
+                         int(rng.integers(1, 3 * pool.n + 6))}):
+            spec = _Probe(n, m)
+            got = greedy_fits(pool, spec)
+            assert got == _fits_all_ways(pool, spec), (n, m)
+            seen.add(got)
+            # a read: the pool is left bit for bit as it was
+            assert pool.load.tobytes() == load
+            assert pool.mem_free.tobytes() == mem_free
+    assert seen == {True, False}
+
+
+@pytest.mark.parametrize("side", ["fits", "does-not-fit"])
+@pytest.mark.parametrize("tasks", [1, 2, 3])
+def test_greedy_fits_one_ulp_either_side_of_the_threshold(side, tasks):
+    """One node with 0.3 free: the request whose ``tasks``-th task is the
+    last to fit, and the next float up, whose ``tasks``-th task does not."""
+    pool = NodePool(1)
+    pool.mem_free[:] = 0.3
+    m = (0.3 + 1e-12) / tasks
+    for _ in range(64):          # walk down to the largest m that fits
+        if _fits_all_ways(pool, _Probe(tasks, m)):
+            break
+        m = np.nextafter(m, -np.inf)
+    while _fits_all_ways(pool, _Probe(tasks, np.nextafter(m, np.inf))):
+        m = np.nextafter(m, np.inf)
+    if side == "does-not-fit":
+        m = np.nextafter(m, np.inf)
+    spec = _Probe(tasks, float(m))
+    assert greedy_fits(pool, spec) is (side == "fits")
+    assert greedy_fits(pool, spec) == _fits_all_ways(pool, spec)
+
+
+@pytest.mark.parametrize("mem_req", [0.5, 1e-300, 0.0])
+def test_greedy_fits_nothing_on_an_empty_pool(mem_req):
+    spec = _Probe(1, mem_req)
+    assert greedy_fits(NodePool(0), spec) is False
+    assert not _fits_all_ways(NodePool(0), spec)
+
+
+@pytest.mark.parametrize("mem_req", [0.0, 1e-300, 1e-13])
+def test_greedy_fits_takes_any_number_of_weightless_tasks(mem_req):
+    """Nodes whose memory a failure zeroed still take a task that asks for
+    (next to) nothing, without end; with none alive nothing fits."""
+    pool = NodePool(3)
+    pool.mem_free[:] = [0.0, 0.0, 0.25]
+    for n in (1, 3, 50):
+        assert greedy_fits(pool, _Probe(n, mem_req)) is True
+        assert _fits_all_ways(pool, _Probe(n, mem_req))
+    pool.mem_free[:] = -1e-9            # below every threshold
+    assert greedy_fits(pool, _Probe(1, mem_req)) is False
+    assert not _fits_all_ways(pool, _Probe(1, mem_req))
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_greedy_fits_failure_dominates(seed):
+    """If (n, m) does not fit a pool, no (n' >= n, m' >= m) does: the fit
+    table is monotone along both axes."""
+    pool, rng = _worn_pool(100 + seed)
+    ns = list(range(1, 3 * pool.n + 3))
+    ms = sorted({float(m) for m in rng.uniform(0.0, 1.0, 24)}
+                | set(_edge_mem_reqs(pool)) | {1e-300, 1.0})
+    table = np.array([[greedy_fits(pool, _Probe(n, m)) for m in ms]
+                      for n in ns])
+    assert table.any() and not table.all()
+    assert not (table[1:] & ~table[:-1]).any()      # rising n never fits more
+    assert not (table[:, 1:] & ~table[:, :-1]).any()  # nor rising m
 
 
 def test_greedy_p_pauses_lowest_priority_first():
